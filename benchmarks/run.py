@@ -103,6 +103,7 @@ def main() -> None:
 
     from . import (cluster_bench, hetero_bench, kernel_micro, paper_figs,
                    roofline_table, traffic_bench)
+    from repro.launch.device import device_line, use_compile_cache
     from repro.launch.serve import default_serve_spec
 
     ap = build_parser(
@@ -117,8 +118,10 @@ def main() -> None:
         spec = spec_from_args(args, base=default_serve_spec()).validate()
     except (KeyError, ValueError) as e:
         ap.error(str(e))
+    use_compile_cache()
 
     def coexec_suite():
+        print(f"# coexec device: {device_line()}", file=sys.stderr)
         structured = hetero_bench.coexec_structured_rows(spec,
                                                          smoke=args.smoke)
         write_bench_doc(args.bench_json, "coexec", spec, structured)

@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import logging
 import threading
 import time
 from typing import Any, Callable, Optional, Sequence
@@ -47,6 +48,8 @@ from typing import Any, Callable, Optional, Sequence
 import numpy as np
 
 from .memory import MemoryModel
+
+_log = logging.getLogger(__name__)
 
 try:  # jax is always present in this repo, but keep the DES importable alone
     import jax
@@ -541,9 +544,10 @@ class DataPlane:
         Warm-up results are discarded; counters are not touched.
 
         Warm-up is best-effort: a kernel that fails to trace or compile
-        is left for the real dispatch path, whose error handling fails
-        the launch through its handle — pre-warming must not turn a
-        launch failure into a submit-time exception.
+        is logged at WARNING with the compiler's message and left for the
+        real dispatch path, whose error handling fails the launch through
+        its handle — pre-warming must not turn a launch failure into a
+        submit-time exception.
 
         Args:
             units: the engine's units (each warms its own jit cache).
@@ -566,12 +570,15 @@ class DataPlane:
             for unit in units:
                 try:
                     unit.prewarm(plan.kernel.fn, args)
-                except Exception:
-                    import logging
-                    logging.getLogger(__name__).debug(
-                        "pre-warm of kernel %r skipped; first dispatch "
-                        "will compile (or fail through its handle)",
-                        plan.kernel.name, exc_info=True)
+                except Exception as exc:
+                    # boundary: a compiler refusal must be visible here,
+                    # but the launch fails through its handle, not submit
+                    _log.warning(
+                        "pre-warm of kernel %r on unit %s failed at bucket "
+                        "%d; its first dispatch will compile (or fail "
+                        "through its handle): %s",
+                        plan.kernel.name, unit.name, bucket, exc,
+                        exc_info=True)
                     return
             if bucket >= top:
                 break

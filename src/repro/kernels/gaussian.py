@@ -30,13 +30,24 @@ def _blur_kernel(s0, s1, s2, s3, s4, o_ref):
                   t[4] * xp[:, 4:W + 4])
 
 
+# Largest (rows x W) f32 block, in elements (1 MiB): the kernel holds six
+# such blocks, double-buffered, plus its temporaries, and all of it must
+# fit the TPU's scoped VMEM. Wide images (5120 columns) get fewer rows.
+_BLOCK_ELEMS = 1 << 18
+
+
 def _blur_blocks(padded: jax.Array, H: int, W: int, bm: int,
                  interpret: bool) -> jax.Array:
     """Run the blur over `padded` (H+4 rows incl. the 2+2 vertical halo).
 
     Returns the (H, W) interior result; rows past H in the last block are
-    computed on zero padding and sliced off.
+    computed on zero padding and sliced off. ``bm`` is capped to the
+    largest power of two (at least 8) whose block fits ``_BLOCK_ELEMS``.
     """
+    cap = 8
+    while cap * 2 * W <= _BLOCK_ELEMS:
+        cap *= 2
+    bm = min(bm, cap, H)
     pm = (-H) % bm
     padded = jnp.pad(padded, ((0, pm), (0, 0)))
     Hp = H + pm
@@ -59,8 +70,7 @@ def gaussian_blur(img: jax.Array, *, bm: int = 128,
                   interpret: bool = True) -> jax.Array:
     """5x5 separable Gaussian blur, zero padding. img: (H, W) float32."""
     H, W = img.shape
-    return _blur_blocks(jnp.pad(img, ((2, 2), (0, 0))), H, W,
-                        min(bm, H), interpret)
+    return _blur_blocks(jnp.pad(img, ((2, 2), (0, 0))), H, W, bm, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret"))
@@ -76,4 +86,4 @@ def gaussian_blur_halo(img: jax.Array, *, bm: int = 128,
     """
     H = img.shape[0] - 4
     W = img.shape[1]
-    return _blur_blocks(img, H, W, min(bm, H), interpret)
+    return _blur_blocks(img, H, W, bm, interpret)

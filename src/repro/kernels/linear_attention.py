@@ -37,7 +37,14 @@ def _gla_kernel(q_ref, k_ref, v_ref, ld_ref, o_ref, state_ref, *,
     v = v_ref[0].astype(jnp.float32)          # (C, Dv)
     ld = ld_ref[0].astype(jnp.float32)        # (1, C) log decays
 
-    cum = jnp.cumsum(ld, axis=1)              # inclusive cumsum (1, C)
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # inclusive cumsum (1, C) as a product with the upper-triangular ones
+    # matrix: Mosaic has no cumsum lowering; HIGHEST keeps it exact in f32
+    cum = jax.lax.dot_general(ld, (row <= col).astype(jnp.float32),
+                              (((1,), (0,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
+                              preferred_element_type=jnp.float32)
     total = cum[0, chunk - 1]                 # log decay over whole chunk
 
     # intra-chunk: A_ij = q_i·k_j · exp(cum_i - cum_j) for i >= j
@@ -47,8 +54,6 @@ def _gla_kernel(q_ref, k_ref, v_ref, ld_ref, o_ref, state_ref, *,
                             preferred_element_type=jnp.float32)  # (C, C)
     ci = jnp.transpose(cum)                   # (C, 1)
     gamma = jnp.exp(ci - cum)                 # (C, C) = exp(cum_i - cum_j)
-    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     a = jnp.where(row >= col, s * gamma, 0.0)
     intra = jax.lax.dot_general(a, v, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
@@ -87,7 +92,10 @@ def linear_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         v = jnp.pad(v, ((0, 0), (0, pt), (0, 0)))
         log_decay = jnp.pad(log_decay, ((0, 0), (0, pt)))
     Tp = T + pt
-    ld = log_decay.reshape(BH, Tp // chunk, chunk)
+    # (BH, 1, Tp) with (1, 1, chunk) blocks: the block's last two dims are
+    # then 1 (the array's own extent) and chunk (a multiple of 128, or all
+    # of Tp), which is the tiling Mosaic accepts on the TPU
+    ld = log_decay.reshape(BH, 1, Tp)
 
     out = pl.pallas_call(
         functools.partial(_gla_kernel, chunk=chunk),
@@ -97,7 +105,7 @@ def linear_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, chunk, Dk), lambda h, c: (h, c, 0)),
             pl.BlockSpec((1, chunk, Dk), lambda h, c: (h, c, 0)),
             pl.BlockSpec((1, chunk, Dv), lambda h, c: (h, c, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda h, c: (h, c, 0)),
+            pl.BlockSpec((1, 1, chunk), lambda h, c: (h, 0, c)),
         ],
         out_specs=pl.BlockSpec((1, chunk, Dv), lambda h, c: (h, c, 0)),
         scratch_shapes=[pltpu.VMEM((Dk, Dv), jnp.float32)],

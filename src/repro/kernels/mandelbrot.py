@@ -20,23 +20,29 @@ def _mandel_kernel(cre_ref, cim_ref, o_ref, *, max_iter: int):
     cre = cre_ref[...]
     cim = cim_ref[...]
 
+    # Mosaic constraints on the loop carry: each vector carry must start
+    # from a value derived from the input block (a splat constant such as
+    # jnp.zeros_like gets a replicated layout the loop body cannot
+    # relayout into), and the alive mask is carried as f32 — a bool carry
+    # fails to legalize the loop's yield.
     def cond(st):
         i, _, _, _, alive = st
-        return (i < max_iter) & jnp.any(alive)
+        return (i < max_iter) & jnp.any(alive > 0.0)
 
     def body(st):
         i, zr, zi, it, alive = st
         zr2, zi2 = zr * zr, zi * zi
-        alive = alive & (zr2 + zi2 <= 4.0)
+        alive = jnp.where(zr2 + zi2 <= 4.0, alive, 0.0)
+        live = alive > 0.0
         zr_n = zr2 - zi2 + cre
         zi_n = 2.0 * zr * zi + cim
-        zr = jnp.where(alive, zr_n, zr)
-        zi = jnp.where(alive, zi_n, zi)
-        it = it + alive.astype(jnp.float32)
+        zr = jnp.where(live, zr_n, zr)
+        zi = jnp.where(live, zi_n, zi)
+        it = it + alive
         return i + 1, zr, zi, it, alive
 
-    st = (jnp.int32(0), jnp.zeros_like(cre), jnp.zeros_like(cim),
-          jnp.zeros_like(cre), jnp.ones(cre.shape, dtype=bool))
+    zero = cre - cre
+    st = (jnp.int32(0), zero, zero, zero, zero + 1.0)
     _, _, _, it, _ = jax.lax.while_loop(cond, body, st)
     o_ref[...] = it
 

@@ -24,7 +24,13 @@ def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # on the TPU a DEFAULT-precision f32 dot takes one bf16 pass (about 1%
+    # error at K=4864), so f32 operands ask for the f32 contraction;
+    # Mosaic accepts HIGHEST only for f32
+    f32 = a_ref.dtype == jnp.float32
     acc_ref[...] += jnp.dot(a_ref[...], b_ref[...],
+                            precision=jax.lax.Precision.HIGHEST if f32
+                            else None,
                             preferred_element_type=jnp.float32)
 
     @pl.when(ik == pl.num_programs(2) - 1)

@@ -8,16 +8,10 @@ and smoke tests must keep seeing 1 device.
 from __future__ import annotations
 
 import jax
-
-try:
-    from jax.sharding import AxisType
-except ImportError:          # older jax: meshes have no axis types
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mk(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -533,6 +533,9 @@ def serve_coexec_traffic(spec) -> None:
 
 
 def serve_coexec_real(spec) -> None:
+    from .device import device_line
+
+    print(f"[serve/coexec] device: {device_line()}")
     for row in coexec_real_rows(spec):
         print(f"[serve/coexec] {row['kernel']}[{row['impl']}]"
               f"/{row['policy']:13s} "
@@ -620,6 +623,9 @@ def main() -> None:
     if args.spec_json:
         print(spec.to_json(indent=2))
         return
+    from .device import use_compile_cache
+
+    use_compile_cache()
     if args.coexec == "real":
         return serve_coexec_real(spec)
     if args.coexec == "sim":

@@ -38,20 +38,8 @@ def set_fsdp(enabled: bool) -> None:
     _FSDP = bool(enabled)
 
 
-def _active_mesh():
-    """The ambient mesh, across jax versions (abstract or `with mesh:`)."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        return get()
-    from jax._src import mesh as mesh_lib
-    mesh = mesh_lib.get_abstract_mesh()
-    if mesh is not None and not getattr(mesh, "empty", True):
-        return mesh
-    return mesh_lib.thread_resources.env.physical_mesh
-
-
 def _mesh_axis_sizes() -> dict[str, int]:
-    mesh = _active_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return {}
     return dict(zip(mesh.axis_names, mesh.axis_sizes))

@@ -184,6 +184,7 @@ def test_benchmarks_cli_rejects_bad_policy_cleanly():
     env = dict(os.environ)
     env["PYTHONPATH"] = str(repo / "src") + os.pathsep + \
         env.get("PYTHONPATH", "")
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.run", "coexec",
          "--policy", "tpyo"],

@@ -20,6 +20,9 @@ def _run_driver(*args: str, cwd=None) -> subprocess.CompletedProcess:
     env = dict(os.environ)
     env["PYTHONPATH"] = str(REPO / "src") + os.pathsep + \
         env.get("PYTHONPATH", "")
+    # benchmarks.run turns on the persistent compile cache; keep test runs
+    # from writing one
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     return subprocess.run([sys.executable, "-m", "benchmarks.run", *args],
                           capture_output=True, text=True, timeout=300,
                           env=env, cwd=cwd or REPO)
