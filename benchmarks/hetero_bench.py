@@ -93,7 +93,6 @@ def coexec_structured_rows(spec=None, *, smoke: bool = False) -> list[dict]:
                        ("kernel", "memory", "policy", "requests", "n",
                         "seconds", "packages", "req_per_s", "items_per_s",
                         "dispatches", "h2d_copies", "d2h_copies",
-                        "device_idle_frac", "host_overhead_frac",
                         "p50_ms", "p99_ms")}))
     return rows
 
@@ -130,7 +129,6 @@ def run_coexec(spec=None, *, smoke: bool = False, structured=None):
                          f"packages={r['packages']};"
                          f"req_per_s={r['req_per_s']:.1f};"
                          f"h2d={r['h2d_copies']};d2h={r['d2h_copies']};"
-                         f"idle={r['device_idle_frac']:.2f};"
                          f"p99_ms={r['p99_ms']:.1f}"))
     return rows
 

@@ -16,7 +16,8 @@ Checks per document:
   ``spec`` (a mapping — the resolved CoexecSpec), ``rows`` (non-empty
   list);
 * every row carries the full required key set for its suite (see
-  ``REQUIRED``), with numeric values where numbers are expected.
+  ``REQUIRED``), plus its ``kind``'s (``REQUIRED_BY_KIND``), with
+  numeric values where numbers are expected.
 
     python scripts/check_bench_schema.py BENCH_coexec.json \\
         BENCH_coexec_multi.json BENCH_kernels.json BENCH_traffic.json \\
@@ -35,11 +36,9 @@ REQUIRED: dict[str, dict[str, set]] = {
     "coexec": {
         "all": {"kind", "workload", "memory", "policy", "seconds",
                 "packages", "dispatches", "h2d_copies", "d2h_copies",
-                "pipeline_depth", "device_idle_frac",
-                "host_overhead_frac"},
+                "pipeline_depth"},
         "numeric": {"seconds", "packages", "dispatches", "h2d_copies",
-                    "d2h_copies", "pipeline_depth", "device_idle_frac",
-                    "host_overhead_frac"},
+                    "d2h_copies", "pipeline_depth"},
     },
     "coexec-multi": {
         "all": {"workload", "tenants", "admission", "fuse", "preempt",
@@ -78,6 +77,13 @@ REQUIRED: dict[str, dict[str, set]] = {
     },
 }
 
+# further numeric keys a row of one ``kind`` must carry, by suite: the
+# DES rows' modeled idle and host shares (the real engine's rows carry
+# none; the profiler's device trace measures those)
+REQUIRED_BY_KIND: dict[str, dict[str, set]] = {
+    "coexec": {"sim": {"device_idle_frac", "host_overhead_frac"}},
+}
+
 
 def check_doc(path: str, doc) -> list[str]:
     """Validate one artifact document; returns error strings."""
@@ -106,10 +112,12 @@ def check_doc(path: str, doc) -> list[str]:
         if not isinstance(row, dict):
             err(f"rows[{i}] is not an object")
             continue
-        missing = sorted(want["all"] - set(row))
+        by_kind = REQUIRED_BY_KIND.get(suite, {}).get(row.get("kind"),
+                                                      set())
+        missing = sorted((want["all"] | by_kind) - set(row))
         if missing:
             err(f"rows[{i}] missing required key(s) {missing}")
-        for key in sorted(want["numeric"] & set(row)):
+        for key in sorted((want["numeric"] | by_kind) & set(row)):
             if not isinstance(row[key], numbers.Number) \
                     or isinstance(row[key], bool):
                 err(f"rows[{i}][{key!r}] must be numeric, "

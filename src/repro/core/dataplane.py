@@ -47,6 +47,7 @@ from typing import Any, Callable, Optional, Sequence
 
 import numpy as np
 
+from . import tracing
 from .memory import MemoryModel
 
 _log = logging.getLogger(__name__)
@@ -299,9 +300,12 @@ class LaunchPlan:
     Built once per submit by :meth:`DataPlane.plan`; worker threads share
     it (counter updates are lock-protected, the arrays are only read and
     the output container is written in disjoint package ranges).
+    ``launch_id`` is the owning launch's id, set by the engine once the
+    launch has one, for the data plane's spans.
     """
 
-    __slots__ = ("kernel", "inputs", "out", "total", "counters", "_lock")
+    __slots__ = ("kernel", "inputs", "out", "total", "counters",
+                 "launch_id", "_lock")
 
     def __init__(self, kernel: CoexecKernel, inputs: list, out: np.ndarray,
                  total: int):
@@ -310,6 +314,7 @@ class LaunchPlan:
         self.out = out
         self.total = int(total)
         self.counters = DataPlaneCounters()
+        self.launch_id = -1
         self._lock = threading.Lock()
 
     def add(self, **deltas: int) -> None:
@@ -468,7 +473,9 @@ class DataPlane:
         Returns:
             The staged argument list for :meth:`issue`.
         """
-        return self._stage(unit, plan, pkg)
+        with tracing.span("coexec.stage", launch=plan.launch_id,
+                          unit=pkg.unit, pkg=pkg.seq):
+            return self._stage(unit, plan, pkg)
 
     def issue(self, unit, plan: LaunchPlan, pkg, args: list):
         """Phase 2 — dispatch the kernel asynchronously on ``unit``.
@@ -489,27 +496,22 @@ class DataPlane:
         """
         plan.add(dispatches=1)
         pkg.t_launch = time.perf_counter()
-        return unit.dispatch(plan.kernel.fn, pkg.offset, args)
+        with tracing.span("coexec.issue", launch=plan.launch_id,
+                          unit=pkg.unit, pkg=pkg.seq):
+            return unit.dispatch(plan.kernel.fn, pkg.offset, args)
 
-    def complete(self, unit, plan: LaunchPlan, pkg, out_dev, *,
-                 busy_floor: float = 0.0) -> None:
-        """Phase 3 — await the device, attribute busy time, land output.
+    def complete(self, unit, plan: LaunchPlan, pkg, out_dev) -> None:
+        """Phase 3 — await the device and land the output.
 
-        Blocks on the device completion event, charges the compute span
-        to ``unit``, collects the result into the plan's output
-        container and stamps ``pkg.t_collected``.
+        Blocks on the device completion event, stamps ``pkg.t_complete``,
+        collects the result into the plan's output container and stamps
+        ``pkg.t_collected``.
 
         Args:
             unit: the unit that ran the package.
             plan: the launch's data-plane state.
             pkg: the package to complete.
             out_dev: the in-flight handle from :meth:`issue`.
-            busy_floor: completion time of the unit's previous package;
-                with several packages in flight their launch→complete
-                spans overlap, so busy time is charged from
-                ``max(t_launch, busy_floor)`` to avoid double-counting
-                the overlapped stretch. ``0.0`` (serial) charges the
-                full launch→complete span, exactly as before the split.
 
         Raises:
             TypeError: ``out_dev`` has no ``block_until_ready`` — an
@@ -526,8 +528,9 @@ class DataPlane:
                 f"synchronize on it (kernels must return a jax array)")
         sync()
         pkg.t_complete = time.perf_counter()
-        unit.add_busy(pkg.t_complete - max(pkg.t_launch, busy_floor))
-        self._collect(plan, pkg, out_dev)
+        with tracing.span("coexec.collect", launch=plan.launch_id,
+                          unit=pkg.unit, pkg=pkg.seq):
+            self._collect(plan, pkg, out_dev)
         pkg.t_collected = time.perf_counter()
 
     def prewarm(self, units: Sequence, plan: LaunchPlan,
@@ -538,7 +541,7 @@ class DataPlane:
         :func:`_package_chunks`), so a launch over ``plan.total`` items
         can only ever present ``O(log total)`` distinct input shapes.
         Tracing + compiling each of them here, at plan-build time, keeps
-        JIT compile time out of ``unit.add_busy`` — a bucket's first
+        JIT compile time out of the package timeline — a bucket's first
         dispatch would otherwise charge the compile to the unit and
         poison the dynamic (hguided / work-stealing) speed estimates.
         Warm-up results are discarded; counters are not touched.

@@ -61,6 +61,7 @@ from typing import Callable, Optional, Sequence
 
 import numpy as np
 
+from . import tracing
 from .admission import (AdmissionConfig, AdmissionController, AdmissionFull,
                         LaunchShed, fusion_bucket)
 from .dataplane import (CoexecKernel, DataPlaneCounters, as_coexec_kernel,
@@ -311,9 +312,9 @@ class RealBackend(Backend):
         Blocks on the device result, lands it in the launch's output
         container and feeds the SpeedBoard. ``busy_floor`` is the
         previous package's completion time on this unit: with several
-        packages in flight their issue→complete spans overlap, so busy
-        time and throughput are measured from whichever is later —
-        issue or the moment the device actually became free.
+        packages in flight their issue→complete spans overlap, so
+        throughput is measured from whichever is later — issue or the
+        moment the device actually became free.
 
         Args:
             unit: index of the serving Coexecution Unit.
@@ -324,8 +325,7 @@ class RealBackend(Backend):
         """
         if out_dev is None:
             return
-        self.plane.complete(self.units[unit], launch.plan, pkg, out_dev,
-                            busy_floor=busy_floor)
+        self.plane.complete(self.units[unit], launch.plan, pkg, out_dev)
         if self.board is not None:
             self.board.record(
                 unit, pkg.size,
@@ -414,6 +414,7 @@ class RealBackend(Backend):
         fused.plan = self.plane.plan(
             as_coexec_kernel(fused.kernel, len(inputs)), inputs, out,
             sched.total)
+        fused.plan.launch_id = launch_id
         # the fused scheduler's index space is *members*; WFQ credit is
         # accounted in work-items, so each member unit costs its whole
         # (bucket-padded) index space — keeps engine fairness on the
@@ -492,7 +493,9 @@ class CoexecEngine:
             1, int(spec.units.pipeline_depth)) if spec is not None else 1
         self.board = SpeedBoard(len(self.units),
                                 hints=[u.speed_hint for u in self.units])
-        self._cv = threading.Condition()
+        # re-entrant, so a worker can time its wait for the lock apart
+        # from the ``with self._cv:`` block it then enters (_take_lock)
+        self._cv = threading.Condition(threading.RLock())
         self.backend = RealBackend(self.units, self.plane, board=self.board,
                                    condition=self._cv)
         self.loop = ExecutionLoop(self.backend,
@@ -701,6 +704,7 @@ class CoexecEngine:
             launch = _Launch(self.loop.next_id(), scheduler, kernel, inputs,
                              out, adaptive)
             launch.plan = plan
+            plan.launch_id = launch.id
             if tenant is not None:
                 launch.tenant = str(tenant)
             launch.weight = float(weight)
@@ -723,6 +727,16 @@ class CoexecEngine:
         return launch.handle
 
     # -- worker loop -------------------------------------------------------
+    def _take_lock(self, unit_idx: int) -> None:
+        """Acquire ``_cv`` for the ``with self._cv:`` block that follows.
+
+        The wait is the ``coexec.lock`` span. ``_cv`` is re-entrant, so
+        the ``with`` then takes it again at once; its body drops this
+        extra hold with ``self._cv.release()`` first thing.
+        """
+        with tracing.span("coexec.lock", unit=unit_idx):
+            self._cv.acquire()
+
     def _retire_oldest(self, unit_idx: int, inflight: collections.deque,
                        busy_floor: float) -> float:
         """Complete the unit's oldest in-flight package, in issue order.
@@ -741,17 +755,21 @@ class CoexecEngine:
             The new busy floor (this package's completion time).
         """
         launch, pkg, out_dev = inflight.popleft()
+        error = None
         try:
             self.backend.finish(unit_idx, launch, pkg, out_dev,
                                 busy_floor=busy_floor)
         except BaseException as e:
-            with self._cv:
-                self.loop.complete(launch, pkg, error=e)
-                self._cv.notify_all()
-            return busy_floor
+            error = e
+        self._take_lock(unit_idx)
         with self._cv:
-            self.loop.complete(launch, pkg)
+            self._cv.release()
+            with tracing.span("coexec.retire", launch=launch.id,
+                              unit=unit_idx, pkg=pkg.seq):
+                self.loop.complete(launch, pkg, error=error)
             self._cv.notify_all()
+        if error is not None:
+            return busy_floor
         return pkg.t_complete or busy_floor
 
     def _worker(self, unit_idx: int) -> None:
@@ -779,8 +797,11 @@ class CoexecEngine:
         inflight: collections.deque = collections.deque()
         busy_floor = 0.0
         while True:
+            self._take_lock(unit_idx)
             with self._cv:
-                work = self.loop.pull(unit_idx, force_flush=self._stop)
+                self._cv.release()
+                with tracing.span("coexec.pull", unit=unit_idx):
+                    work = self.loop.pull(unit_idx, force_flush=self._stop)
                 while work is None:
                     if inflight:
                         # nothing new to pull: drain the pipeline instead
@@ -794,9 +815,13 @@ class CoexecEngine:
                     # change notifies the condition, so an untimed wait
                     # needs no poll-interval safety net.
                     ripen = self.admission.next_ripen_in(time.perf_counter())
-                    self.backend.wait_next_event(
-                        timeout=None if ripen is None else max(ripen, 1e-4))
-                    work = self.loop.pull(unit_idx, force_flush=self._stop)
+                    with tracing.span("coexec.park", unit=unit_idx):
+                        self.backend.wait_next_event(
+                            timeout=None if ripen is None
+                            else max(ripen, 1e-4))
+                    with tracing.span("coexec.pull", unit=unit_idx):
+                        work = self.loop.pull(unit_idx,
+                                              force_flush=self._stop)
             if work is None:
                 busy_floor = self._retire_oldest(unit_idx, inflight,
                                                  busy_floor)

@@ -67,6 +67,11 @@ class LaunchStats:
     explicit H2D/D2H staging copies/bytes — so the USM-vs-BUFFERS
     distinction of the configured :class:`~.memory.MemoryModel` is
     observable per launch (USM performs zero staging copies).
+
+    ``t_submit`` (when the launch was offered to admission) and
+    ``t_first_issue`` (the earliest ``t_issue`` of its packages) are on
+    the backend's clock; their difference is the launch's wait for
+    admission and a free unit.
     """
 
     total_s: float
@@ -74,6 +79,8 @@ class LaunchStats:
     unit_busy_s: dict[str, float]
     data: DataPlaneCounters = dataclasses.field(
         default_factory=DataPlaneCounters)
+    t_submit: float = 0.0
+    t_first_issue: float = 0.0
 
     @property
     def num_packages(self) -> int:
@@ -610,7 +617,10 @@ class ExecutionLoop:
             total_s=end - launch.t_submit,
             packages=list(launch.done_pkgs),
             unit_busy_s=self._busy_of(launch.done_pkgs),
-            data=self.backend.launch_counters(launch))
+            data=self.backend.launch_counters(launch),
+            t_submit=launch.t_submit,
+            t_first_issue=min((p.t_issue for p in launch.done_pkgs),
+                              default=end))
         self.backend.deliver(launch)
 
     def _demux_fused(self, fused: LaunchState, end: float) -> None:
@@ -641,6 +651,10 @@ class ExecutionLoop:
                 cover.t_complete - cover.t_issue, 0.0) / members_in_cover
             self.backend.commit_member(fused, m, i, cover)
             m.finalized = True
+            first = min(p.t_issue for p in pkgs
+                        if p.offset < start + span
+                        and start < p.offset + p.size)
             m.stats = LaunchStats(total_s=end - m.t_submit, packages=[mp],
-                                  unit_busy_s=busy, data=shares[i])
+                                  unit_busy_s=busy, data=shares[i],
+                                  t_submit=m.t_submit, t_first_issue=first)
             self.backend.deliver(m)

@@ -40,6 +40,7 @@ import numpy as np
 
 import jax
 
+from . import tracing
 from .dataplane import CoexecKernel
 from .engine import CoexecEngine, LaunchHandle, LaunchStats
 from .units import JaxUnit
@@ -216,19 +217,23 @@ class CoexecutorRuntime:
             AdmissionFull: engine at capacity and ``block=False``.
             ValueError: invalid scheduler parameters for this policy.
         """
-        engine = self._get_engine()
-        n = len(engine.units)
-        sched_spec = self._spec.scheduler
-        if granularity != 1:
-            sched_spec = sched_spec.replace(granularity=granularity)
-        sched = sched_spec.build(total, n, speeds=self._spec.speeds_for(n))
-        if out is None:
-            if isinstance(kernel, CoexecKernel):
-                out = kernel.alloc_out(total, inputs)
-            else:
-                out = np.zeros((total, *out_trailing_shape), dtype=out_dtype)
-        return engine.submit(sched, kernel, inputs, out,
-                             tenant=tenant, weight=weight, block=block)
+        with tracing.span("coexec.submit", tenant=tenant or "",
+                          items=int(total)):
+            engine = self._get_engine()
+            n = len(engine.units)
+            sched_spec = self._spec.scheduler
+            if granularity != 1:
+                sched_spec = sched_spec.replace(granularity=granularity)
+            sched = sched_spec.build(total, n,
+                                     speeds=self._spec.speeds_for(n))
+            if out is None:
+                if isinstance(kernel, CoexecKernel):
+                    out = kernel.alloc_out(total, inputs)
+                else:
+                    out = np.zeros((total, *out_trailing_shape),
+                                   dtype=out_dtype)
+            return engine.submit(sched, kernel, inputs, out,
+                                 tenant=tenant, weight=weight, block=block)
 
     def launch(self, total: int, kernel: Callable,
                inputs: Sequence[np.ndarray],

@@ -15,8 +15,8 @@ Package kernels keep the signature ``fn(offset, chunk_inputs...) ->
 chunk_out``; *how* the chunks reach the unit (zero-copy USM views vs
 staged per-package buffers, padding to size buckets) is decided by the
 data plane (:mod:`repro.core.dataplane`), which drives :meth:`JaxUnit.
-dispatch`. The unit itself only owns the device, the per-kernel jit
-cache, and its busy-time accounting.
+dispatch`. The unit itself only owns the device and the per-kernel jit
+cache.
 """
 from __future__ import annotations
 
@@ -81,7 +81,6 @@ class JaxUnit:
         self.speed_hint = float(speed_hint)
         self._compiled: dict[Any, Any] = {}
         self._aot: dict[Any, Any] = {}  # guarded-by: _lock
-        self.busy_s = 0.0
         self._lock = threading.Lock()
 
     def compiled(self, fn: Callable) -> Any:
@@ -137,8 +136,8 @@ class JaxUnit:
         shapes/dtypes *without executing it* (safe for kernels whose
         bodies do host callbacks), and parks the executable where
         :meth:`dispatch` finds it — so the first real dispatch of this
-        bucket skips XLA compilation and none of it is charged to
-        :attr:`busy_s`. Memoized per ``(kernel, shapes, dtypes)``: later
+        bucket skips XLA compilation and none of it lands in a package's
+        timeline. Memoized per ``(kernel, shapes, dtypes)``: later
         launches presenting the same compile bucket skip straight
         through.
 
@@ -159,8 +158,3 @@ class JaxUnit:
                 jax.ShapeDtypeStruct((), np.int32), *avals).compile()
         with self._lock:
             self._aot.setdefault(key, exe)
-
-    def add_busy(self, seconds: float) -> None:
-        """Account dispatch-to-completion time against this unit."""
-        with self._lock:
-            self.busy_s += seconds

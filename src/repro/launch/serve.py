@@ -112,23 +112,18 @@ def coexec_real_rows(spec=None, *, policies=None, units=None) -> list[dict]:
             scheduler=spec.scheduler.replace(policy=policy))
         with CoexecutorRuntime.from_spec(pspec, units=units) as rt:
             rt.launch(n, kernel, datas[0])          # warm the jit cache
-            busy0 = sum(u.busy_s for u in units)
             t0 = time.perf_counter()
             served, pkgs, lats, inflight = 0, 0, [], []
             h2d, d2h, dispatches = 0, 0, 0
-            host_s = 0.0        # staging + collection (non-compute) time
             service = []        # (t_complete, tenant, items) per package
 
             def _reap(h, t_sub, tenant):
-                nonlocal served, pkgs, h2d, d2h, dispatches, host_s
+                nonlocal served, pkgs, h2d, d2h, dispatches
                 h.result()
                 served, pkgs = served + 1, pkgs + h.stats.num_packages
                 h2d += h.stats.data.h2d_copies
                 d2h += h.stats.data.d2h_copies
                 dispatches += h.stats.data.dispatches
-                host_s += sum((p.t_launch - p.t_issue)
-                              + (p.t_collected - p.t_complete)
-                              for p in h.stats.packages)
                 service.extend((p.t_complete, tenant, p.size)
                                for p in h.stats.packages)
                 lats.append(time.perf_counter() - t_sub)
@@ -142,7 +137,6 @@ def coexec_real_rows(spec=None, *, policies=None, units=None) -> list[dict]:
             for h, t_sub, tenant in inflight:
                 _reap(h, t_sub, tenant)
             dt = time.perf_counter() - t0
-            busy = sum(u.busy_s for u in units) - busy0
         lats.sort()
         # fairness of throughput across requests + the time-sampled
         # service fairness curve (the measure --preempt tightens), on a
@@ -164,9 +158,6 @@ def coexec_real_rows(spec=None, *, policies=None, units=None) -> list[dict]:
                          items_per_s=served * n / dt,
                          dispatches=dispatches,
                          h2d_copies=h2d, d2h_copies=d2h,
-                         device_idle_frac=max(
-                             0.0, 1.0 - busy / (len(units) * dt)),
-                         host_overhead_frac=host_s / dt,
                          fairness=jain_index(thru),
                          fairness_curve_mean=float(sum(curve) / len(curve)),
                          fairness_curve_min=float(min(curve)),

@@ -79,3 +79,20 @@ def test_kernels_artifact_passes_schema_check(tmp_path):
     assert cbs.check_doc(str(path), doc) == []
     assert doc["suite"] == "kernels"
     assert doc["schema_version"] == cbs.SCHEMA_VERSION
+
+
+def test_real_rows_carry_no_host_clock_idle_columns():
+    """The real engine's rows drop the host-clock idle and host shares
+    (two units on one device counted its time twice); the DES rows keep
+    their modeled ones."""
+    from repro.launch.serve import (coexec_real_rows, coexec_sim_rows,
+                                    default_serve_spec)
+
+    spec = default_serve_spec()
+    spec = spec.replace(workload=spec.workload.replace(
+        name="taylor", items=256, requests=2, concurrent=2))
+    idle = {"device_idle_frac", "host_overhead_frac"}
+    real = coexec_real_rows(spec, policies=("dyn4",))
+    assert real and all(not idle & set(r) for r in real)
+    sim = coexec_sim_rows(spec, policies=("dyn4",))
+    assert sim and all(idle <= set(r) for r in sim)

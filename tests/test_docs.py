@@ -128,3 +128,34 @@ def test_examples_import_the_spec_api():
         text = (REPO / "examples" / name).read_text()
         assert "from repro.api import" in text, name
         assert "CoexecSpec" in text, name
+
+
+def test_coexec_schema_asks_the_idle_columns_of_des_rows_only():
+    """The real engine's rows carry no host-clock idle or host shares;
+    the DES rows keep theirs, which are model outputs."""
+    sys.path.insert(0, str(REPO / "scripts"))
+    try:
+        import check_bench_schema as cbs
+    finally:
+        sys.path.pop(0)
+
+    base = {"workload": "taylor", "memory": "usm", "policy": "hguided",
+            "seconds": 1.0, "packages": 4, "dispatches": 4,
+            "h2d_copies": 0, "d2h_copies": 0, "pipeline_depth": 1}
+    sim = dict(base, kind="sim", device_idle_frac=0.1,
+               host_overhead_frac=0.2)
+    real = dict(base, kind="real")
+    doc = {"schema_version": cbs.SCHEMA_VERSION, "suite": "coexec",
+           "spec": {}, "rows": [sim, real]}
+    assert cbs.check_doc("good.json", doc) == []
+    bare_sim = {k: v for k, v in sim.items() if k != "device_idle_frac"}
+    errors = cbs.check_doc("b.json", dict(doc, rows=[bare_sim, real]))
+    assert any("device_idle_frac" in e for e in errors)
+
+
+def test_every_span_is_documented_in_architecture():
+    from repro.core import tracing
+
+    text = (REPO / "docs" / "architecture.md").read_text()
+    missing = [name for name in tracing.SPANS if f"`{name}`" not in text]
+    assert tracing.SPANS and not missing, missing

@@ -14,7 +14,7 @@ Acceptance for the pipelining tentpole and its satellites:
   has *all* of its in-flight packages disowned and re-issued exactly
   once, with covers and counter totals identical to an undisturbed run;
 * **compile warm-up** (satellite) — ``JaxUnit.prewarm`` AOT-compiles
-  without executing the kernel body and charges nothing to ``busy_s``;
+  without executing the kernel body;
   ``CoexecEngine.submit`` warms every package bucket before dispatch;
 * **exact park wait** (satellite) — an idle engine holding a staged
   fusion group wakes at the ripen deadline, not on a coarse poll;
@@ -175,7 +175,7 @@ def test_kill_mid_pipeline_reissues_all_inflight_exactly_once():
 
 
 # ---------------------------------------------------------------------------
-# Satellite: compile warm-up is AOT and never charged to busy clocks
+# Satellite: compile warm-up is AOT and never runs the kernel body
 # ---------------------------------------------------------------------------
 
 def test_prewarm_compiles_without_executing_or_charging_busy(shared_units):
@@ -190,10 +190,8 @@ def test_prewarm_compiles_without_executing_or_charging_busy(shared_units):
 
     unit = shared_units[0]
     args = [np.ones(64, np.float32)]
-    busy0 = unit.busy_s
     unit.prewarm(body, args)
     assert calls == [], "prewarm must not execute the kernel body"
-    assert unit.busy_s == busy0, "warm-up charged to the busy clock"
     # the warmed executable computes the same thing the jit path does
     out = unit.dispatch(body, 0, args)
     out.block_until_ready()
@@ -206,7 +204,7 @@ def test_prewarm_compiles_without_executing_or_charging_busy(shared_units):
 def test_submit_prewarms_every_bucket_before_dispatch(shared_units):
     """The engine warms each power-of-two package bucket at submit time,
     so the first dispatch of every bucket runs a precompiled executable
-    (XLA compile time never lands in ``busy_s``/SpeedBoard samples)."""
+    (XLA compile time never lands in the SpeedBoard's samples)."""
     kernel = as_coexec_kernel(lambda off, c: c * 3.0, 1)  # fresh fn object
     inputs = [np.random.default_rng(0).normal(size=N).astype(np.float32)]
     warmed0 = {id(u): len(u._aot) for u in shared_units}
