@@ -26,10 +26,15 @@ Two pieces make the distinction real:
   :class:`BuffersDataPlane` stages each package's slices with
   ``jax.device_put``, dispatches on the staged buffers, and copies results
   back through a per-package buffer before merging. Both are instrumented:
-  every launch carries :class:`DataPlaneCounters` (dispatches, H2D/D2H
-  staging copies and bytes) surfaced in
+  every launch carries :class:`DataPlaneCounters` (dispatches, device
+  executions, H2D/D2H staging copies and bytes) surfaced in
   :class:`~repro.core.engine.LaunchStats`, so ``MemorySpec`` finally
   selects observable behavior end-to-end.
+
+Both planes run a package as at most two exact-cover *pieces*
+(:func:`pieces`), each padded to a power-of-two compile bucket that
+:meth:`DataPlane.prewarm` has already built, so the bucket pad of a
+package no longer doubles what crosses between host and device.
 
 On this CPU-only substrate "device memory" and host memory coincide, so
 the USM plane's zero-copy claim is literal (numpy views over the shared
@@ -62,7 +67,7 @@ except ImportError:  # pragma: no cover
 __all__ = [
     "ArgRole", "ArgSpec", "OutputSpec", "CoexecKernel", "as_coexec_kernel",
     "DataPlaneCounters", "LaunchPlan", "DataPlane", "UsmDataPlane",
-    "BuffersDataPlane", "make_plane",
+    "BuffersDataPlane", "make_plane", "pieces",
 ]
 
 
@@ -242,17 +247,21 @@ class DataPlaneCounters:
     """Copy/dispatch accounting of one launch (or one simulated run).
 
     Attributes:
-        dispatches: package executions issued to the units.
+        dispatches: packages issued to the units, one per package.
+        pieces: device executions those packages ran as (see
+            :func:`pieces`); equals ``dispatches`` when no package split.
         h2d_copies: explicit host→device staging copies (``device_put``
-            of a package slice or broadcast operand). Zero under USM.
+            of a piece's slice, or of a package's broadcast operand).
+            Zero under USM.
         h2d_bytes: bytes moved by those staging copies.
-        d2h_copies: explicit device→host copy-backs through a per-package
+        d2h_copies: explicit device→host copy-backs through a per-piece
             buffer before the merge. Zero under USM (results land in the
             shared container directly).
         d2h_bytes: bytes moved by those copy-backs.
     """
 
     dispatches: int = 0
+    pieces: int = 0
     h2d_copies: int = 0
     h2d_bytes: int = 0
     d2h_copies: int = 0
@@ -294,6 +303,13 @@ class DataPlaneCounters:
         return dataclasses.asdict(self)
 
 
+def _item_bytes(arr, axis: int) -> int:
+    """Bytes of one index along ``axis`` of ``arr``."""
+    shape = np.shape(arr)
+    return np.dtype(arr.dtype).itemsize * int(
+        np.prod(shape[:axis] + shape[axis + 1:]))
+
+
 class LaunchPlan:
     """Per-launch data-plane state: bound kernel, arrays, counters.
 
@@ -301,11 +317,14 @@ class LaunchPlan:
     it (counter updates are lock-protected, the arrays are only read and
     the output container is written in disjoint package ranges).
     ``launch_id`` is the owning launch's id, set by the engine once the
-    launch has one, for the data plane's spans.
+    launch has one, for the data plane's spans. ``item_bytes`` is what
+    one index of the split arguments and the output holds, and ``least``
+    the smallest bucket :meth:`DataPlane.prewarm` built; :func:`pieces`
+    reads both.
     """
 
     __slots__ = ("kernel", "inputs", "out", "total", "counters",
-                 "launch_id", "_lock")
+                 "launch_id", "item_bytes", "least", "_lock")
 
     def __init__(self, kernel: CoexecKernel, inputs: list, out: np.ndarray,
                  total: int):
@@ -315,7 +334,16 @@ class LaunchPlan:
         self.total = int(total)
         self.counters = DataPlaneCounters()
         self.launch_id = -1
+        self.item_bytes = _item_bytes(out, 0) + sum(
+            _item_bytes(arr, spec.axis)
+            for spec, arr in zip(kernel.args, inputs)
+            if spec.role is ArgRole.SPLIT)
+        self.least = 1
         self._lock = threading.Lock()
+
+    def pieces(self, pkg) -> list[tuple[int, int, int]]:
+        """:func:`pieces` of one package of this launch."""
+        return pieces(pkg.size, self.item_bytes, self.least)
 
     def add(self, **deltas: int) -> None:
         """Atomically bump counter fields by the given deltas."""
@@ -335,6 +363,52 @@ def _bucket(size: int) -> int:
     while b < size:
         b <<= 1
     return b
+
+
+#: Pad bytes a split has to save before it pays for one more dispatch,
+#: one more H2D copy and one more D2H copy. Measured on one TPU v5e with
+#: ``scripts/transfer_probe.py --packages``: alone, a dispatch takes
+#: 0.55-0.62 ms, an H2D copy 0.64-0.82 ms + bytes at 6.3-6.6 GB/s and a
+#: D2H copy 0.06-0.48 ms + bytes at 5.6-6.3 GB/s; inside a package the
+#: copies' round trips overlap, and a second piece that leaves out
+#: almost no pad costs 0.65-0.95 ms. Each pad byte left out saves about
+#: 0.5 ns: its host zero-fill, its copy, and the kernel's work on it.
+#: 0.8 ms / 0.5 ns = 1.6 MB, where the measured package times cross.
+_MIN_SPLIT_BYTES = 1_600_000
+
+
+def pieces(size: int, bytes_per_item: float,
+           least: int = 1) -> list[tuple[int, int, int]]:
+    """Exact-cover pieces of one package, each on a power-of-two bucket.
+
+    A power-of-two ``size`` is one piece. Otherwise the pieces are the
+    largest power of two ``2**a <= size`` and the rest in its own
+    bucket, provided that saves at least :data:`_MIN_SPLIT_BYTES` of
+    pad against the whole package's bucket; else ``size`` is one piece
+    in ``_bucket(size)``. No bucket is below ``least`` or above
+    ``max(_bucket(size), least)``, so every one is a bucket that the
+    launch's :meth:`DataPlane.prewarm` builds.
+
+    Args:
+        size: items of the package.
+        bytes_per_item: bytes one item moves: the split arguments' and
+            the output's (``LaunchPlan.item_bytes``).
+        least: smallest bucket a piece may take.
+
+    Returns:
+        ``(start, piece_size, bucket)`` per piece, ``start`` relative to
+        the package's offset, in order.
+    """
+    whole = _bucket(size)
+    top = whole if whole == size else whole >> 1
+    if top == size or top < least:
+        return [(0, size, max(whole, least))]
+    rest = size - top
+    tail = max(_bucket(rest), least)
+    saved = (whole - top - tail) * bytes_per_item
+    if saved <= 0 or saved < _MIN_SPLIT_BYTES:
+        return [(0, size, whole)]
+    return [(0, top, top), (top, rest, tail)]
 
 
 def _split_view(arr: np.ndarray, spec: ArgSpec, offset: int, size: int,
@@ -357,12 +431,12 @@ def _fill_split(buf: np.ndarray, arr: np.ndarray, spec: ArgSpec,
                 offset: int, size: int, total: int) -> None:
     """Assemble one split chunk in place in a reused staging buffer.
 
-    Writes exactly the values :func:`_package_chunks` would produce for
-    the same package — interior slice, zero-filled halo at the edges,
+    Writes exactly the values :func:`_piece_chunks` would produce for
+    the same piece — interior slice, zero-filled halo at the edges,
     zero bucket pad — into ``buf`` (whose split-axis extent must already
-    be ``size + 2*halo + grow``), so the BUFFERS plane's staged operands
-    stay bitwise identical to the USM plane's without a fresh pad
-    allocation per package.
+    be ``bucket + 2*halo``), so the BUFFERS plane's staged operands stay
+    bitwise identical to the USM plane's without a fresh pad allocation
+    per piece. Only what the slice does not overwrite is zeroed.
     """
     lo = offset - spec.halo
     hi = offset + size + spec.halo
@@ -370,30 +444,31 @@ def _fill_split(buf: np.ndarray, arr: np.ndarray, spec: ArgSpec,
     index = [slice(None)] * arr.ndim
     index[spec.axis] = slice(max(lo, 0), min(hi, total))
     view = arr[tuple(index)]
+    end = lo_pad + view.shape[spec.axis]
     dst = [slice(None)] * buf.ndim
-    dst[spec.axis] = slice(lo_pad, lo_pad + view.shape[spec.axis])
-    buf.fill(0)
+    for part in (slice(0, lo_pad), slice(end, None)):
+        dst[spec.axis] = part
+        buf[tuple(dst)] = 0
+    dst[spec.axis] = slice(lo_pad, end)
     buf[tuple(dst)] = view
 
 
-def _package_chunks(plan: LaunchPlan, pkg):
-    """Yield ``(spec, chunk)`` per argument for one package.
+def _piece_chunks(plan: LaunchPlan, offset: int, size: int, bucket: int):
+    """Yield ``(spec, chunk)`` per argument for one piece.
 
-    Split args are sliced to the package range (plus halo) and
-    zero-padded up to the package's power-of-two size bucket; broadcast
-    args pass through whole. The bucket pad is applied identically by
-    both data planes — it is compile-shape management (bounding XLA
-    recompilation), not data movement, and keeping the shapes equal
-    across planes is what makes USM-vs-BUFFERS results bitwise identical
-    (the same executable runs on the same values).
+    Split args are sliced to the piece's range (plus halo) and
+    zero-padded up to the piece's power-of-two bucket; broadcast args
+    pass through whole. Both data planes cut the same pieces on the same
+    buckets — compile-shape management (bounding XLA recompilation),
+    which is what makes USM-vs-BUFFERS results bitwise identical (the
+    same executable runs on the same values).
     """
-    grow = _bucket(pkg.size) - pkg.size
     for spec, arr in zip(plan.kernel.args, plan.inputs):
         if spec.role is ArgRole.SPLIT:
-            chunk = _split_view(arr, spec, pkg.offset, pkg.size, plan.total)
-            if grow:
+            chunk = _split_view(arr, spec, offset, size, plan.total)
+            if bucket > size:
                 pad = [(0, 0)] * chunk.ndim
-                pad[spec.axis] = (0, grow)
+                pad[spec.axis] = (0, bucket - size)
                 chunk = np.pad(chunk, pad)
         else:
             chunk = arr
@@ -403,10 +478,11 @@ def _package_chunks(plan: LaunchPlan, pkg):
 class DataPlane:
     """Data-movement strategy for one memory model (template class).
 
-    Subclasses implement :meth:`_stage` (how package inputs reach the
-    unit) and :meth:`_collect` (how the result lands in the launch's
-    output container); :meth:`execute` runs the shared dispatch protocol
-    and timestamps the package.
+    Subclasses implement :meth:`_stage` (how a package's pieces' inputs
+    reach the unit) and :meth:`_collect` (how their results land in the
+    launch's output container); :meth:`execute` runs the shared dispatch
+    protocol and timestamps the package. A package is one pull, one
+    retire and one set of stamps however many pieces it runs as.
     """
 
     model: MemoryModel
@@ -471,75 +547,83 @@ class DataPlane:
             pkg: the package whose inputs to materialize.
 
         Returns:
-            The staged argument list for :meth:`issue`.
+            ``(offset, size, args)`` per piece, ``offset`` global, for
+            :meth:`issue`.
         """
         with tracing.span("coexec.stage", launch=plan.launch_id,
                           unit=pkg.unit, pkg=pkg.seq):
             return self._stage(unit, plan, pkg)
 
-    def issue(self, unit, plan: LaunchPlan, pkg, args: list):
-        """Phase 2 — dispatch the kernel asynchronously on ``unit``.
+    def issue(self, unit, plan: LaunchPlan, pkg, staged: list) -> list:
+        """Phase 2 — dispatch the package's pieces asynchronously.
 
-        Stamps ``pkg.t_launch`` and counts the dispatch, but does *not*
-        wait for the device: the returned handle is an un-materialized
-        device value whose completion :meth:`complete` later awaits, so
-        the caller may overlap further staging with the compute.
+        Stamps ``pkg.t_launch`` and counts one dispatch and its pieces,
+        but does *not* wait for the device: the returned handles are
+        un-materialized device values whose completion :meth:`complete`
+        later awaits, so the caller may overlap further staging with the
+        compute. Each piece runs at its own global offset.
 
         Args:
             unit: the executing unit.
             plan: the launch's data-plane state.
             pkg: the package being dispatched.
-            args: staged arguments from :meth:`stage`.
+            staged: the pieces from :meth:`stage`.
 
         Returns:
-            The in-flight device output handle.
+            ``(offset, size, out_dev)`` per piece, in flight.
         """
-        plan.add(dispatches=1)
+        plan.add(dispatches=1, pieces=len(staged))
         pkg.t_launch = time.perf_counter()
+        outs = []
         with tracing.span("coexec.issue", launch=plan.launch_id,
-                          unit=pkg.unit, pkg=pkg.seq):
-            return unit.dispatch(plan.kernel.fn, pkg.offset, args)
+                          unit=pkg.unit, pkg=pkg.seq, pieces=len(staged)):
+            for offset, size, args in staged:
+                out_dev = unit.dispatch(plan.kernel.fn, offset, args)
+                self._issued(out_dev)
+                outs.append((offset, size, out_dev))
+        return outs
 
-    def complete(self, unit, plan: LaunchPlan, pkg, out_dev) -> None:
+    def complete(self, unit, plan: LaunchPlan, pkg, outs: list) -> None:
         """Phase 3 — await the device and land the output.
 
-        Blocks on the device completion event, stamps ``pkg.t_complete``,
-        collects the result into the plan's output container and stamps
-        ``pkg.t_collected``.
+        Blocks on every piece's completion event, stamps
+        ``pkg.t_complete``, collects each piece's items into the plan's
+        output container and stamps ``pkg.t_collected``.
 
         Args:
             unit: the unit that ran the package.
             plan: the launch's data-plane state.
             pkg: the package to complete.
-            out_dev: the in-flight handle from :meth:`issue`.
+            outs: the in-flight pieces from :meth:`issue`.
 
         Raises:
-            TypeError: ``out_dev`` has no ``block_until_ready`` — an
-                unknown output type the async path cannot synchronize
+            TypeError: a piece's output has no ``block_until_ready`` —
+                an unknown output type the async path cannot synchronize
                 on (a silent no-sync here would hand :meth:`_collect`
                 a result that may still be materializing).
         """
-        sync = getattr(out_dev, "block_until_ready", None)
-        if sync is None:
-            raise TypeError(
-                f"kernel {plan.kernel.name!r} returned "
-                f"{type(out_dev).__name__!r}, which has no "
-                f"block_until_ready; the pipelined data plane cannot "
-                f"synchronize on it (kernels must return a jax array)")
-        sync()
+        for _, _, out_dev in outs:
+            sync = getattr(out_dev, "block_until_ready", None)
+            if sync is None:
+                raise TypeError(
+                    f"kernel {plan.kernel.name!r} returned "
+                    f"{type(out_dev).__name__!r}, which has no "
+                    f"block_until_ready; the pipelined data plane cannot "
+                    f"synchronize on it (kernels must return a jax array)")
+            sync()
         pkg.t_complete = time.perf_counter()
         with tracing.span("coexec.collect", launch=plan.launch_id,
                           unit=pkg.unit, pkg=pkg.seq):
-            self._collect(plan, pkg, out_dev)
+            self._collect(plan, pkg, outs)
         pkg.t_collected = time.perf_counter()
 
     def prewarm(self, units: Sequence, plan: LaunchPlan,
                 granularity: int) -> None:
         """Compile every package bucket on every unit before dispatch.
 
-        Package slices are padded to power-of-two compile buckets (see
-        :func:`_package_chunks`), so a launch over ``plan.total`` items
-        can only ever present ``O(log total)`` distinct input shapes.
+        Package pieces are padded to power-of-two compile buckets (see
+        :func:`pieces`), so a launch over ``plan.total`` items can only
+        ever present ``O(log total)`` distinct input shapes.
         Tracing + compiling each of them here, at plan-build time, keeps
         JIT compile time out of the package timeline — a bucket's first
         dispatch would otherwise charge the compile to the unit and
@@ -558,7 +642,7 @@ class DataPlane:
             granularity: package alignment — the smallest bucket is
                 ``_bucket(granularity)``.
         """
-        bucket = _bucket(max(int(granularity), 1))
+        bucket = plan.least = _bucket(max(int(granularity), 1))
         top = _bucket(plan.total)
         while True:
             args = []
@@ -591,7 +675,10 @@ class DataPlane:
     def _stage(self, unit, plan: LaunchPlan, pkg) -> list:
         raise NotImplementedError
 
-    def _collect(self, plan: LaunchPlan, pkg, out_dev) -> None:
+    def _issued(self, out_dev) -> None:
+        """Called on each piece's output as soon as it is dispatched."""
+
+    def _collect(self, plan: LaunchPlan, pkg, outs: list) -> None:
         raise NotImplementedError
 
 
@@ -603,48 +690,53 @@ class UsmDataPlane(DataPlane):
     args are passed whole), and the result is written straight into the
     launch's shared output container — the paper's "collection is free"
     USM semantics (Fig. 2b). No ``device_put``, no copy-back buffer:
-    ``h2d_copies == d2h_copies == 0`` by construction. (Both planes pad
-    split chunks to a power-of-two compile bucket — shape management
-    shared with BUFFERS, see :func:`_package_chunks` — which is not
-    data movement and is not counted.)
+    ``h2d_copies == d2h_copies == 0`` by construction. (Both planes cut
+    a package into the same pieces and pad each to a power-of-two
+    compile bucket — shape management shared with BUFFERS, see
+    :func:`_piece_chunks` — which is not data movement and is not
+    counted.)
     """
 
     model = MemoryModel.USM
 
     def _stage(self, unit, plan: LaunchPlan, pkg) -> list:
-        return [chunk for _, chunk in _package_chunks(plan, pkg)]
+        return [(pkg.offset + start, size,
+                 [chunk for _, chunk in _piece_chunks(
+                     plan, pkg.offset + start, size, bucket)])
+                for start, size, bucket in plan.pieces(pkg)]
 
-    def _collect(self, plan: LaunchPlan, pkg, out_dev) -> None:
+    def _collect(self, plan: LaunchPlan, pkg, outs: list) -> None:
         # in-place landing in the one shared allocation — the USM no-op
         # collection (no intermediate per-package buffer is materialized)
-        plan.out[pkg.offset:pkg.offset + pkg.size] = out_dev[:pkg.size]
+        for offset, size, out_dev in outs:
+            plan.out[offset:offset + size] = out_dev[:size]
 
 
 class BuffersDataPlane(DataPlane):
     """Per-package buffers data plane: explicit staging in, copy-back out.
 
-    Each package's split slices (and its broadcast operands — buffers are
-    per-package in this model, as in the paper's SYCL Buffers mode where
-    accessors are re-created for every command group) are staged with
-    ``jax.device_put`` to the unit's device; the result is copied back
-    into a per-package host buffer and then merged into the output
-    container. Every copy increments the plan's counters. Staged values
-    are identical to the USM plane's chunks (same slice + halo + bucket
-    pad as :func:`_package_chunks`, assembled in place), which is what
-    makes USM-vs-BUFFERS results *bitwise* identical for a fixed package
-    structure — the same executable runs on the same values; only the
-    data movement differs.
+    Each piece's split slices are staged with ``jax.device_put`` to the
+    unit's device, and each package's broadcast operands once, shared by
+    its pieces (buffers are per-package in this model, as in the paper's
+    SYCL Buffers mode where accessors are re-created for every command
+    group); each piece's result is copied back into a host buffer — the
+    copy starts as soon as the piece is dispatched — and then merged
+    into the output container. Every copy increments the plan's
+    counters. Staged values are identical to the USM plane's chunks
+    (same pieces, slice + halo + bucket pad as :func:`_piece_chunks`,
+    assembled in place), which is what makes USM-vs-BUFFERS results
+    *bitwise* identical for a fixed package structure — the same
+    executables run on the same values; only the data movement differs.
 
     Split-argument staging goes through a per-unit scratch pool: the
-    host buffer a package's slice is assembled in is keyed by
+    host buffer a piece's slice is assembled in is keyed by
     ``(unit, shape, dtype)`` — one compile bucket, one allocation — and
     returned to the pool when the package collects, instead of a fresh
     pad allocation per ``device_put``. A package in flight holds its
     scratch exclusively, so pipelined staging of package *k+1* can never
     overwrite buffers package *k* is still computing on. The pool is
-    reuse of *allocations*, not of data movement: every package still
-    pays its per-argument H2D copy and per-package D2H copy-back, so the
-    counters are unchanged.
+    reuse of *allocations*, not of data movement: every piece still
+    pays its per-argument H2D copy and its D2H copy-back.
     """
 
     model = MemoryModel.BUFFERS
@@ -666,32 +758,50 @@ class BuffersDataPlane(DataPlane):
         return key, buf
 
     def _stage(self, unit, plan: LaunchPlan, pkg) -> list:
-        grow = _bucket(pkg.size) - pkg.size
-        args, lease = [], []
-        for spec, arr in zip(plan.kernel.args, plan.inputs):
-            if spec.role is ArgRole.SPLIT:
+        copies = nbytes = 0
+        shared = {}
+        for i, (spec, arr) in enumerate(zip(plan.kernel.args, plan.inputs)):
+            if spec.role is ArgRole.BROADCAST:
+                shared[i] = jax.device_put(arr, unit.device)
+                copies, nbytes = copies + 1, nbytes + np.asarray(arr).nbytes
+        staged, lease = [], []
+        for start, size, bucket in plan.pieces(pkg):
+            args = []
+            for i, (spec, arr) in enumerate(zip(plan.kernel.args,
+                                                plan.inputs)):
+                if i in shared:
+                    args.append(shared[i])
+                    continue
                 shape = list(arr.shape)
-                shape[spec.axis] = pkg.size + 2 * spec.halo + grow
+                shape[spec.axis] = bucket + 2 * spec.halo
                 key, buf = self._borrow(unit, shape, arr.dtype)
-                _fill_split(buf, arr, spec, pkg.offset, pkg.size,
+                _fill_split(buf, arr, spec, pkg.offset + start, size,
                             plan.total)
-                staged = jax.device_put(buf, unit.device)
+                args.append(jax.device_put(buf, unit.device))
                 lease.append((key, buf))
-            else:
-                staged = jax.device_put(arr, unit.device)
-                buf = arr
-            plan.add(h2d_copies=1, h2d_bytes=np.asarray(buf).nbytes)
-            args.append(staged)
+                copies, nbytes = copies + 1, nbytes + buf.nbytes
+            staged.append((pkg.offset + start, size, args))
+        plan.add(h2d_copies=copies, h2d_bytes=nbytes)
         if lease:
             with self._pool_lock:
                 self._leases[(id(plan), pkg.seq)] = lease
-        return args
+        return staged
 
-    def _collect(self, plan: LaunchPlan, pkg, out_dev) -> None:
-        # copy-back through a separate per-package buffer, then merge
-        host = np.asarray(out_dev)
-        plan.add(d2h_copies=1, d2h_bytes=host.nbytes)
-        plan.out[pkg.offset:pkg.offset + pkg.size] = host[:pkg.size]
+    def _issued(self, out_dev) -> None:
+        # start the copy-back now, so a second piece adds its bytes but
+        # not a second round trip
+        start = getattr(out_dev, "copy_to_host_async", None)
+        if start is not None:
+            start()
+
+    def _collect(self, plan: LaunchPlan, pkg, outs: list) -> None:
+        # copy-back through a separate per-piece buffer, then merge
+        nbytes = 0
+        for offset, size, out_dev in outs:
+            host = np.asarray(out_dev)
+            nbytes += host.nbytes
+            plan.out[offset:offset + size] = host[:size]
+        plan.add(d2h_copies=len(outs), d2h_bytes=nbytes)
         with self._pool_lock:
             for key, buf in self._leases.pop((id(plan), pkg.seq), ()):
                 self._scratch.setdefault(key, []).append(buf)

@@ -39,7 +39,7 @@ from typing import Optional, Sequence
 import numpy as np
 
 from .admission import AdmissionConfig, coerce_admission, fusion_bucket
-from .dataplane import DataPlaneCounters
+from .dataplane import DataPlaneCounters, _bucket, pieces
 from .energy import EnergyReport, PowerModel, energy_report
 from .exec import Backend, ExecutionLoop, LaunchState
 from .memory import MemoryCosts, MemoryModel
@@ -83,10 +83,10 @@ class SimResult:
     ``data`` mirrors the real engine's per-launch
     :class:`~.dataplane.DataPlaneCounters`, and since both substrates now
     share one control plane it is literally produced by the same
-    finalization code (the modeled dispatch count and the staging copies
-    the memory model implies: one H2D and one D2H per package under
-    BUFFERS, none under USM) — spec-driven real-vs-sim comparisons read
-    the same counter surface.
+    finalization code (the modeled dispatch count, the pieces each
+    package runs as, and the staging copies the memory model implies:
+    one H2D and one D2H per piece under BUFFERS, none under USM) —
+    spec-driven real-vs-sim comparisons read the same counter surface.
     """
 
     workload: str
@@ -120,14 +120,23 @@ class SimResult:
 
 
 def _count_package(counters: DataPlaneCounters, memory: MemoryModel,
-                   in_bytes: float, out_bytes: float) -> None:
-    """Model one package's data-plane accounting (mirrors the real planes)."""
+                   size: int, workload: Workload, least: int) -> None:
+    """Model one package's data-plane accounting (mirrors the real planes).
+
+    One dispatch, run as the :func:`~repro.core.dataplane.pieces` the
+    real planes cut; under BUFFERS each piece stages one input and
+    copies back one output, of its bucket's bytes.
+    """
+    parts = pieces(size, workload.bytes_in_per_item
+                   + workload.bytes_out_per_item, least)
     counters.dispatches += 1
+    counters.pieces += len(parts)
     if memory is MemoryModel.BUFFERS:
-        counters.h2d_copies += 1
-        counters.h2d_bytes += int(in_bytes)
-        counters.d2h_copies += 1
-        counters.d2h_bytes += int(out_bytes)
+        items = sum(bucket for _, _, bucket in parts)
+        counters.h2d_copies += len(parts)
+        counters.h2d_bytes += int(items * workload.bytes_in_per_item)
+        counters.d2h_copies += len(parts)
+        counters.d2h_bytes += int(items * workload.bytes_out_per_item)
 
 
 def _item_costs(workload: Workload, unit: "SimUnit") -> np.ndarray:
@@ -220,10 +229,10 @@ class SimBackend(Backend):
         """
         wl = launch.workload
         u = self.units[unit]
-        in_bytes = pkg.size * wl.bytes_in_per_item
         out_bytes = pkg.size * wl.bytes_out_per_item
-        _count_package(self.counters, self.memory, in_bytes, out_bytes)
-        _count_package(launch.counters, self.memory, in_bytes, out_bytes)
+        least = _bucket(getattr(launch.scheduler, "granularity", 1))
+        for counters in (self.counters, launch.counters):
+            _count_package(counters, self.memory, pkg.size, wl, least)
 
         launch_cost, t_launch, compute_end = \
             self._model_compute(unit, launch, pkg)

@@ -9,17 +9,23 @@
   allocate from the declared slot);
 * the kernel registry behaves like the scheduler/workload registries:
   introspection, strict option validation, third-party registration
-  (the retired ``package_kernel`` shim is gone — see tests/test_api.py).
+  (the retired ``package_kernel`` shim is gone — see tests/test_api.py);
+* exact-cover pieces: a package runs as at most two pre-warmed
+  power-of-two pieces that cover it exactly, both planes cut the same
+  pieces, and the bytes a launch moves stay near its own items.
 """
 import numpy as np
 import jax.numpy as jnp
 import pytest
 
+from _propcheck import given, settings, st
+
 from repro.api import (CoexecSpec, build_kernel, kernel_demo_inputs,
                        kernel_names, kernel_plugin, register_kernel,
                        registry_listing, temporary_plugins)
 from repro.core import (ArgRole, ArgSpec, CoexecEngine, CoexecKernel,
-                        CoexecutorRuntime, OutputSpec)
+                        CoexecutorRuntime, OutputSpec, dataplane)
+from repro.core.dataplane import _bucket, pieces
 from repro.kernels import ref
 
 PAPER_KERNELS = ("gaussian", "mandelbrot", "matmul", "rap", "ray", "taylor")
@@ -39,6 +45,12 @@ def base_spec(memory: str = "usm", policy: str = "hguided") -> CoexecSpec:
 def shared_units():
     """One unit set for the whole module (warm jit caches across tests)."""
     return base_spec().build_units()
+
+
+@pytest.fixture
+def split_every_package(monkeypatch):
+    """Split whenever a split saves any pad (test sizes save little)."""
+    monkeypatch.setattr(dataplane, "_MIN_SPLIT_BYTES", 0)
 
 
 def run_engine(memory, kernel, inputs, units, policy="hguided"):
@@ -327,3 +339,190 @@ def test_sim_counters_mirror_memory_model():
         assert r.data.d2h_copies == copies * r.num_packages
         if mem == "buffers":
             assert r.data.h2d_bytes > 0 and r.data.d2h_bytes > 0
+
+
+# ---------------------------------------------------------------------------
+# Exact-cover pieces
+# ---------------------------------------------------------------------------
+
+def _check_pieces(parts, size, least=1):
+    assert 1 <= len(parts) <= 2
+    at = 0
+    for start, piece, bucket in parts:
+        assert start == at and piece > 0
+        assert bucket >= piece and bucket & (bucket - 1) == 0
+        assert least <= bucket <= max(_bucket(size), least)
+        at += piece
+    assert at == size
+
+
+@pytest.mark.parametrize("min_split", [0, None])
+@pytest.mark.parametrize("size", [1, 3, 256, 700, 1 << 20, (1 << 20) + 256,
+                                  3 << 19, (1 << 24) + 96, 16_872_192])
+def test_pieces_cover_the_package_exactly(size, min_split, monkeypatch):
+    if min_split is not None:
+        monkeypatch.setattr(dataplane, "_MIN_SPLIT_BYTES", min_split)
+    parts = pieces(size, 12.0)
+    _check_pieces(parts, size)
+    if size & (size - 1) == 0:
+        assert parts == [(0, size, size)]          # never split
+    if len(parts) == 2:
+        top = parts[0][1]
+        assert top & (top - 1) == 0 and top <= size < 2 * top
+        saved = (_bucket(size) - top - parts[1][2]) * 12.0
+        assert saved > 0 and saved >= dataplane._MIN_SPLIT_BYTES
+
+
+def test_pieces_split_only_where_the_saved_pad_pays():
+    # 16.87M items sit in a 33.55M bucket: 2**24 + 2**17 saves ~200 MB
+    assert pieces(16_872_192, 12.0) == [(0, 1 << 24, 1 << 24),
+                                        (1 << 24, 94_976, 1 << 17)]
+    # 3 * 2**7 saves 128 items (1.5 kB) of pad: not worth a dispatch
+    assert pieces(384, 12.0) == [(0, 384, 512)]
+    # a 96-item rest still takes the smallest pre-warmed bucket, as does
+    # a last package smaller than the granularity
+    assert pieces((1 << 20) + 96, 12.0, least=256)[1] == (1 << 20, 96, 256)
+    assert pieces(96, 12.0, least=256) == [(0, 96, 256)]
+
+
+@settings(max_examples=60, deadline=None)
+@given(total=st.integers(256, 1 << 22), grain_log2=st.integers(0, 10),
+       speed=st.floats(0.1, 0.9))
+def test_piece_buckets_are_ones_prewarm_builds(total, grain_log2, speed):
+    """Every piece of every package of an hguided launch runs on a
+    bucket ``prewarm`` compiles: from ``_bucket(granularity)`` up to
+    ``_bucket(total)``."""
+    from repro.core import HGuidedScheduler
+
+    grain = min(1 << grain_log2, total)
+    sched = HGuidedScheduler(total, 2, speeds=[speed, 1 - speed],
+                             granularity=grain)
+    saved = dataplane._MIN_SPLIT_BYTES
+    dataplane._MIN_SPLIT_BYTES = 0
+    try:
+        unit = 0
+        while (pkg := sched.next_package(unit)) is not None:
+            parts = pieces(pkg.size, 8.0, least=_bucket(grain))
+            _check_pieces(parts, pkg.size, _bucket(grain))
+            for _, _, bucket in parts:
+                assert _bucket(grain) <= bucket <= _bucket(total)
+            unit ^= 1
+    finally:
+        dataplane._MIN_SPLIT_BYTES = saved
+
+
+#: the one-chip mandelbrot cell: 70.3M items, granularity 256, hints
+#: 0.4/0.6, 8 B in and 4 B out per item
+CELL_ITEMS, CELL_GRAIN, CELL_HINTS = 70_300_000, 256, (0.4, 0.6)
+
+
+def test_cell_hguided_sequence_moves_at_most_1_10x_its_items():
+    """The DES replays the one-chip cell's hguided packages at full size
+    with the real rule: what crosses is within 10% of the launch's own
+    12 B an item (it was 1.56x on the whole-package buckets), and some
+    packages split."""
+    from repro.core import SimUnit, Workload, simulate
+
+    wl = Workload(name="mandelbrot-t1", total=CELL_ITEMS,
+                  bytes_in_per_item=8.0, bytes_out_per_item=4.0,
+                  working_set_bytes=12.0 * CELL_ITEMS)
+    units = [SimUnit(f"tpu{i}", "tpu", speed=1e9 * h)
+             for i, h in enumerate(CELL_HINTS)]
+    spec = (CoexecSpec.builder().policy("hguided").granularity(CELL_GRAIN)
+            .units(count=2, speed_hints=CELL_HINTS).dist(CELL_HINTS[0])
+            .memory("buffers").build())
+    r = simulate(None, units, wl, spec=spec)
+    moved = r.data.h2d_bytes + r.data.d2h_bytes
+    assert moved <= 1.10 * 12 * CELL_ITEMS, moved / (12 * CELL_ITEMS)
+    assert r.data.pieces > r.data.dispatches == r.num_packages
+
+
+def test_real_plane_on_the_cell_sequence_at_reduced_size(monkeypatch):
+    """The real BUFFERS plane runs the cell's hguided package sequence,
+    at 1/64 of its items with the split threshold scaled alike: it moves
+    at most 1.10x the launch's bytes, some packages split, and the
+    escape counts equal the reference's bit for bit."""
+    import jax
+
+    from repro.core import HGuidedScheduler, counits_from_devices
+    from repro.core.dataplane import make_plane
+    from repro.core.memory import MemoryModel
+
+    scale = 64
+    total = CELL_ITEMS // scale
+    monkeypatch.setattr(dataplane, "_MIN_SPLIT_BYTES",
+                        dataplane._MIN_SPLIT_BYTES / scale)
+    kernel = build_kernel("mandelbrot")
+    cre, cim = kernel_demo_inputs("mandelbrot", total, seed=3)
+    (unit,) = counits_from_devices(jax.local_devices()[:1])
+    plane = make_plane(MemoryModel.BUFFERS)
+    out = kernel.alloc_out(total, [cre, cim])
+    plan = plane.plan(kernel, [cre, cim], out, total)
+    plane.prewarm([unit], plan, CELL_GRAIN)
+    sched = HGuidedScheduler(total, 2, speeds=list(CELL_HINTS),
+                             granularity=CELL_GRAIN)
+    turn = 0
+    while (pkg := sched.next_package(turn)) is not None:
+        plane.execute(unit, plan, pkg)
+        turn ^= 1
+    c = plan.counters
+    assert c.h2d_bytes + c.d2h_bytes <= 1.10 * 12 * total
+    assert c.pieces > c.dispatches
+    want = np.asarray(ref.mandelbrot(jnp.asarray(cre), jnp.asarray(cim)))
+    assert np.array_equal(out, want)
+
+
+@pytest.mark.parametrize("name", PAPER_KERNELS)
+def test_split_packages_bitwise_usm_buffers_reference(
+        name, shared_units, monkeypatch):
+    """With every package split in two, USM and BUFFERS agree bit for
+    bit, and with the reference: the same launch run on whole-package
+    buckets (and mandelbrot's and rap's plain references). Gaussian's
+    halo crosses each piece boundary. MatMul's dot sums over the shared
+    axis, whose result XLA may round per chunk shape, so it is held to
+    ``a @ b`` within float tolerance there."""
+    kernel = build_kernel(name)
+    inputs = kernel_demo_inputs(name, N, seed=7)
+    whole, _ = run_engine("usm", kernel, inputs, shared_units,
+                          policy="dyn16")
+    monkeypatch.setattr(dataplane, "_MIN_SPLIT_BYTES", 0)
+    usm_out, usm_stats = run_engine("usm", kernel, inputs, shared_units,
+                                    policy="dyn16")
+    buf_out, buf_stats = run_engine("buffers", kernel, inputs, shared_units,
+                                    policy="dyn16")
+    assert usm_stats.data.pieces == buf_stats.data.pieces \
+        == 2 * buf_stats.num_packages                 # 44-item packages
+    assert np.array_equal(usm_out, buf_out)
+    if name == "matmul":
+        np.testing.assert_allclose(buf_out, inputs[0] @ inputs[1],
+                                   rtol=1e-5, atol=1e-5)
+    else:
+        assert np.array_equal(buf_out, whole)
+    if name in ("mandelbrot", "rap"):
+        want = getattr(ref, name)(*map(jnp.asarray, inputs))
+        assert np.array_equal(buf_out, np.asarray(want))
+    # one D2H per piece; one H2D per piece per split arg, plus broadcasts
+    n_split = sum(a.role is ArgRole.SPLIT for a in kernel.args)
+    d = buf_stats.data
+    assert d.d2h_copies == d.pieces
+    assert d.h2d_copies == d.pieces * n_split + d.dispatches * (
+        len(kernel.args) - n_split)
+
+
+def test_broadcast_staged_once_per_package(shared_units,
+                                           split_every_package):
+    """MatMul's B is staged once per package and shared by its pieces."""
+    kernel = build_kernel("matmul")
+    a, b = kernel_demo_inputs("matmul", N, seed=3)
+    out, stats = run_engine("buffers", kernel, [a, b], shared_units,
+                            policy="dyn16")
+    d = stats.data
+    assert d.pieces > d.dispatches == stats.num_packages
+    assert d.h2d_copies == d.pieces + d.dispatches      # A per piece, B once
+    assert d.h2d_bytes == (d.dispatches * b.nbytes
+                           + sum(a[:1].nbytes * bucket
+                                 for p in stats.packages
+                                 for _, _, bucket in pieces(
+                                     p.size, a[:1].nbytes
+                                     + out[:1].nbytes)))
+    np.testing.assert_allclose(out, a @ b, rtol=1e-5, atol=1e-5)

@@ -15,7 +15,7 @@ import pytest
 
 from repro.api import CoexecSpec, build_scheduler
 from repro.core import (CoexecEngine, MemoryModel, SimUnit,
-                        counits_from_devices, simulate,
+                        counits_from_devices, dataplane, simulate,
                         validate_cover, Workload)
 
 TOTAL = 4096
@@ -122,5 +122,32 @@ def test_work_stealing_memory_models_parity(memory):
     # (the sim charges one H2D + one D2H per package under BUFFERS; the
     # real plane pays one H2D per argument — one here — plus one D2H)
     assert h.stats.data.dispatches == r.data.dispatches
+    assert h.stats.data.pieces == r.data.pieces
     assert (h.stats.data.h2d_copies == r.data.h2d_copies) and \
         (h.stats.data.d2h_copies == r.data.d2h_copies)
+
+
+@pytest.mark.parametrize("memory", [MemoryModel.USM, MemoryModel.BUFFERS])
+def test_des_and_engine_agree_on_pieces(memory, monkeypatch):
+    """With every package split, the DES cuts the same exact-cover
+    pieces as the real data plane for the same package sequence (work
+    stealing's is fixed up front): the same dispatches, pieces, copies
+    and bytes, on the same pre-warmed buckets."""
+    monkeypatch.setattr(dataplane, "_MIN_SPLIT_BYTES", 0)
+    wl = regular_workload()
+    r = simulate(sched("work_stealing"), sim_units(), wl, memory=memory)
+    data = np.arange(TOTAL, dtype=np.float32)
+    spec = CoexecSpec.builder().memory(memory.value).build()
+    with CoexecEngine.from_spec(spec, units=real_units()) as engine:
+        h = engine.submit(sched("work_stealing"), lambda off, c: c * 3.0,
+                          [data], np.zeros(TOTAL, np.float32))
+        out = h.result(timeout=120)
+    np.testing.assert_allclose(out, data * 3.0)
+    assert sorted((p.offset, p.size) for p in h.stats.packages) == \
+        sorted((p.offset, p.size) for p in r.packages)
+    real, des = h.stats.data, r.data
+    assert des.pieces > des.dispatches
+    assert (real.dispatches, real.pieces) == (des.dispatches, des.pieces)
+    assert (real.h2d_copies, real.h2d_bytes, real.d2h_copies,
+            real.d2h_bytes) == (des.h2d_copies, des.h2d_bytes,
+                                des.d2h_copies, des.d2h_bytes)

@@ -26,6 +26,7 @@ import time
 
 import numpy as np
 import jax
+import jax.numpy as jnp
 import pytest
 
 from repro.api import CoexecSpec, build_kernel, build_scheduler, \
@@ -213,6 +214,38 @@ def test_submit_prewarms_every_bucket_before_dispatch(shared_units):
         assert len(u._aot) > warmed0[id(u)], (
             f"{u.name}: submit left no ahead-of-time executables")
     validate_cover(stats.packages, N)
+
+
+@pytest.mark.parametrize("memory", ("usm", "buffers"))
+def test_split_packages_pipelined_bitwise_and_prewarmed(memory, shared_units,
+                                                        monkeypatch):
+    """At depth 2, packages split into two pieces give the serial
+    output bit for bit (retire completes each package's pieces in issue
+    order, each at its own global offset), and no unit builds a program
+    after the pre-warm: the
+    ahead-of-time executables stay as the first submit left them, and
+    the jit fallback never compiles."""
+    from repro.core import dataplane
+
+    monkeypatch.setattr(dataplane, "_MIN_SPLIT_BYTES", 0)
+    def at_index(off, c):        # each item adds its global index
+        return c * 3.0 + (jnp.arange(c.shape[0]) + off).astype(c.dtype)
+
+    kernel = as_coexec_kernel(at_index, 1)
+    inputs = [np.random.default_rng(5).normal(size=N).astype(np.float32)]
+    base, base_stats = run_engine(memory, "dyn16", 1, kernel, inputs,
+                                  shared_units)
+    warmed = {id(u): set(u._aot) for u in shared_units}
+    out, stats = run_engine(memory, "dyn16", 2, kernel, inputs,
+                            shared_units)
+    assert stats.data.pieces > stats.data.dispatches == stats.num_packages
+    assert stats.data.pieces == base_stats.data.pieces
+    assert np.array_equal(base, out)
+    np.testing.assert_allclose(
+        out, inputs[0] * 3.0 + np.arange(N, dtype=np.float32), rtol=1e-6)
+    for u in shared_units:
+        assert set(u._aot) == warmed[id(u)], u.name
+        assert u.compiled(kernel.fn)._cache_size() == 0, u.name
 
 
 # ---------------------------------------------------------------------------

@@ -142,7 +142,10 @@ def test_package_spans_carry_ids_and_lie_inside_the_package(traced, depth):
     by_key = collections.defaultdict(dict)
     for name in PACKAGE_SPANS + ("coexec.retire",):
         for s, e, ids in _spans(_own(events, depth), name):
-            assert set(ids) == {"launch", "unit", "pkg"}, (name, ids)
+            # the issue span also says how many pieces the package ran as
+            extra = {"pieces"} if name == "coexec.issue" else set()
+            assert set(ids) == {"launch", "unit", "pkg"} | extra, (name, ids)
+            assert ids.get("pieces", 1) in (1, 2), (name, ids)
             if ids["launch"] == h.launch_id:
                 key = (ids["unit"], ids["pkg"])
                 assert name not in by_key[key], f"{name} twice for {key}"
