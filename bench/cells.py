@@ -7,8 +7,13 @@ new cell adds files and entries and edits nothing:
 - ``bench/configs/<config>.json``: the deployment's sizes (the entry's
   ``file``), whose ``kernel`` names the plain reference
   ``bench/refs/<kernel>.py``;
+- ``bench/tests/small/<config>.json``: the configuration keys that cut
+  it to a size the CPU tests run in about a second;
 - ``bench/traffic/<traffic>.json``: the load the generator offers;
 - ``bench/metrics/<metric>.py``: one reader per per-layer metric.
+
+The tests in ``bench/tests/`` then hold every cell to the same checks,
+whatever the shape of its kernel's arguments.
 """
 from __future__ import annotations
 
