@@ -258,6 +258,9 @@ class DataPlaneCounters:
             buffer before the merge. Zero under USM (results land in the
             shared container directly).
         d2h_bytes: bytes moved by those copy-backs.
+        halo_bytes: the part of ``h2d_bytes`` that is halo rows, the
+            context a stencil's split slice carries on both sides (zero
+            for kernels with no halo, and under USM).
     """
 
     dispatches: int = 0
@@ -266,6 +269,7 @@ class DataPlaneCounters:
     h2d_bytes: int = 0
     d2h_copies: int = 0
     d2h_bytes: int = 0
+    halo_bytes: int = 0
 
     @property
     def staging_copies(self) -> int:
@@ -736,7 +740,10 @@ class BuffersDataPlane(DataPlane):
     scratch exclusively, so pipelined staging of package *k+1* can never
     overwrite buffers package *k* is still computing on. The pool is
     reuse of *allocations*, not of data movement: every piece still
-    pays its per-argument H2D copy and its D2H copy-back.
+    pays its per-argument H2D copy and its D2H copy-back. The fill of
+    each split argument's scratch, halo rows included, is one
+    ``coexec.fill`` span; the halo rows' bytes are counted apart in
+    ``halo_bytes``.
     """
 
     model = MemoryModel.BUFFERS
@@ -765,6 +772,7 @@ class BuffersDataPlane(DataPlane):
                 shared[i] = jax.device_put(arr, unit.device)
                 copies, nbytes = copies + 1, nbytes + np.asarray(arr).nbytes
         staged, lease = [], []
+        halo_bytes = 0
         for start, size, bucket in plan.pieces(pkg):
             args = []
             for i, (spec, arr) in enumerate(zip(plan.kernel.args,
@@ -775,13 +783,18 @@ class BuffersDataPlane(DataPlane):
                 shape = list(arr.shape)
                 shape[spec.axis] = bucket + 2 * spec.halo
                 key, buf = self._borrow(unit, shape, arr.dtype)
-                _fill_split(buf, arr, spec, pkg.offset + start, size,
-                            plan.total)
+                # each argument's copy starts before the next one's fill
+                with tracing.span("coexec.fill", launch=plan.launch_id,
+                                  unit=pkg.unit, pkg=pkg.seq, rows=size,
+                                  halo=spec.halo):
+                    _fill_split(buf, arr, spec, pkg.offset + start, size,
+                                plan.total)
                 args.append(jax.device_put(buf, unit.device))
                 lease.append((key, buf))
                 copies, nbytes = copies + 1, nbytes + buf.nbytes
+                halo_bytes += 2 * spec.halo * _item_bytes(buf, spec.axis)
             staged.append((pkg.offset + start, size, args))
-        plan.add(h2d_copies=copies, h2d_bytes=nbytes)
+        plan.add(h2d_copies=copies, h2d_bytes=nbytes, halo_bytes=halo_bytes)
         if lease:
             with self._pool_lock:
                 self._leases[(id(plan), pkg.seq)] = lease

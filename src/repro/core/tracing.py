@@ -10,7 +10,9 @@ discrete-event simulator runs in virtual time and emits none.
 The ids: ``launch`` is the launch id (``LaunchHandle.launch_id``; a
 fused batch's own id for its packages), ``unit`` the Coexecution Unit's
 index, ``pkg`` the package's ``seq``; ``coexec.submit`` carries the
-``tenant`` and the launch's ``items``.
+``tenant`` and the launch's ``items``; ``coexec.fill``, one for each
+split argument of each piece, the piece's ``rows`` (items along the
+split axis) and the argument's ``halo``.
 """
 from __future__ import annotations
 
@@ -20,7 +22,8 @@ __all__ = ["SPANS", "span"]
 
 #: every span the program emits, from the entry down to the data plane
 SPANS = ("coexec.submit", "coexec.lock", "coexec.pull", "coexec.park",
-         "coexec.stage", "coexec.issue", "coexec.collect", "coexec.retire")
+         "coexec.stage", "coexec.fill", "coexec.issue", "coexec.collect",
+         "coexec.retire")
 
 
 def span(name: str, **ids) -> jax.profiler.TraceAnnotation:

@@ -195,14 +195,13 @@ def _taylor_inputs(n: int, rng) -> list:
 def _gaussian_kernel_impl(*, impl: str) -> CoexecKernel:
     """Separable 5x5 blur; rows split with a 2-row zero-filled halo.
 
-    The halo is what the pre-protocol closure faked with five pre-shifted
-    input copies: the data plane now hands each package its row range
-    plus two rows of context on either side (zeros beyond the image, as
-    in the reference's zero padding), so co-executed output matches
+    The data plane hands each package its row range plus two rows of
+    context on either side (zeros beyond the image, as in the
+    reference's zero padding), so co-executed output matches
     :func:`repro.kernels.ref.gaussian_blur` on the full image exactly.
     The Pallas variant consumes the same halo'd chunk through
-    :func:`~repro.kernels.gaussian.gaussian_blur_halo` (halo-aware
-    BlockSpecs over the pre-shifted views).
+    :func:`~repro.kernels.gaussian.gaussian_blur_halo`, one device op
+    whose blocks read their own halo rows.
     """
     if impl == "pallas":
         def fn(offset, img):

@@ -14,8 +14,9 @@
   power-of-two pieces that cover it exactly, both planes cut the same
   pieces, and the bytes a launch moves stay near its own items.
 """
-import numpy as np
+import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from _propcheck import given, settings, st
@@ -128,6 +129,52 @@ def test_gaussian_halo_matches_monolithic_reference(shared_units):
     out, _ = run_engine("usm", kernel, [img], shared_units, policy="dyn8")
     want = np.asarray(ref.gaussian_blur(jnp.asarray(img)))
     np.testing.assert_allclose(out, want, rtol=1e-6, atol=1e-7)
+
+
+@pytest.mark.parametrize("name", ("gaussian", "mandelbrot"))
+def test_static_split_matches_unsplit_reference_and_counts_halo_bytes(
+        name, shared_units, split_every_package):
+    """The paper's static policy, 0.4/0.6 over two units, BUFFERS: 256
+    rows on one unit and 384 on the other, run as 256 + 128 pieces, give
+    the whole-image reference exactly. Each piece carries its 2 + 2 halo
+    rows, which ``halo_bytes`` counts inside ``h2d_bytes``; mandelbrot
+    has no halo, and USM counts no copies, so both count none."""
+    rows = 640
+    kernel = build_kernel(name)
+    inputs = kernel_demo_inputs(name, rows, seed=13)
+    runs = {}
+    for memory in ("buffers", "usm"):
+        spec = base_spec(memory, "static")
+        with CoexecEngine.from_spec(spec, units=shared_units) as engine:
+            h = engine.submit(spec.build_scheduler(rows, len(shared_units)),
+                              kernel, inputs, kernel.alloc_out(rows, inputs))
+            runs[memory] = (h.result(timeout=120).copy(), h.stats)
+    out, stats = runs["buffers"]
+    assert sorted(p.size for p in stats.packages) == [256, 384]
+    d = stats.data
+    assert d.pieces == 3
+    # jitted, as the kernel is: XLA fuses both the same way
+    whole = jax.jit(ref.gaussian_blur if name == "gaussian"
+                    else ref.mandelbrot)
+    want = np.asarray(whole(*map(jnp.asarray, inputs)))
+    assert np.array_equal(out, want)
+    if name == "gaussian":
+        width = inputs[0].shape[1]
+        assert d.halo_bytes == d.pieces * 4 * width * 4
+        assert d.h2d_bytes == (256 + 256 + 128 + 3 * 4) * width * 4
+    else:
+        assert d.halo_bytes == 0
+    assert np.array_equal(runs["usm"][0], out)
+    assert runs["usm"][1].data.halo_bytes == 0
+
+
+def test_halo_bytes_survive_split_snapshot_and_to_dict():
+    from repro.core import DataPlaneCounters
+
+    c = DataPlaneCounters(pieces=3, h2d_bytes=100, halo_bytes=7)
+    assert sum(s.halo_bytes for s in c.split(3)) == 7
+    assert c.snapshot().halo_bytes == 7 and c.snapshot() is not c
+    assert c.to_dict()["halo_bytes"] == 7
 
 
 def test_ray_broadcast_default_fills_missing_scene(shared_units):
@@ -526,3 +573,4 @@ def test_broadcast_staged_once_per_package(shared_units,
                                      p.size, a[:1].nbytes
                                      + out[:1].nbytes)))
     np.testing.assert_allclose(out, a @ b, rtol=1e-5, atol=1e-5)
+

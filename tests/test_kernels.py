@@ -6,8 +6,8 @@ import pytest
 from numpy.testing import assert_allclose
 
 from repro.kernels import (demo_spheres, flash_attention, gaussian_blur,
-                           linear_attention, mandelbrot, matmul, rap,
-                           raytrace, ref, taylor_sin)
+                           gaussian_blur_halo, linear_attention, mandelbrot,
+                           matmul, rap, raytrace, ref, taylor_sin)
 
 rng = np.random.default_rng(42)
 
@@ -31,6 +31,22 @@ def test_matmul(m, k, n, dtype):
 def test_gaussian(h, w, bm):
     img = jnp.asarray(rng.normal(size=(h, w)), jnp.float32)
     assert_allclose(gaussian_blur(img, bm=bm), ref.gaussian_blur(img),
+                    rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("h,w,bm,top", [(128, 256, 64, 0),
+                                        (256, 5120, 128, 40),
+                                        (64, 130, 16, 9), (120, 128, 128, 3)])
+def test_gaussian_halo(h, w, bm, top):
+    """The data plane's entry blurs rows ``top .. top + h`` of a larger
+    image from those rows and two of context on either side (zeros past
+    the image's edges), as the whole-image reference does. The piece has
+    ``h + 4`` rows, so the last block's 8-row halo block runs past it."""
+    full = rng.normal(size=(top + h + 5, w))
+    img = jnp.asarray(np.pad(full, ((2, 2), (0, 0))), jnp.float32)
+    piece = img[top:top + h + 4]
+    want = ref.gaussian_blur(img[2:-2])[top:top + h]
+    assert_allclose(gaussian_blur_halo(piece, bm=bm), want,
                     rtol=1e-5, atol=1e-5)
 
 

@@ -103,6 +103,29 @@ def test_engine_kernel_compiles_for_v5e(name, bucket, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("rows", (128, 1024, 2048, 8192))
+def test_gaussian_is_one_device_op(rows, one_chip):
+    """At Table 1's smallest and largest buckets, and at the 1024 and
+    2048 rows the Table-1 cell's pieces run on, the data plane's entry
+    compiles to the Pallas custom call alone: no fusion builds shifted
+    copies of the piece before it. The op keeps the entry's name, which
+    the benchmark's device-trace readers look for."""
+    from repro.kernels import gaussian_blur_halo
+
+    def gaussian_blur_halo_entry(x):
+        return gaussian_blur_halo(x, interpret=False)
+
+    compiled = _compile(gaussian_blur_halo_entry,
+                        [((rows + 4, 5120), jnp.float32)], one_chip)
+    entry = compiled.as_text().split("\nENTRY ", 1)[1].split("\n}", 1)[0]
+    ops = [line.strip() for line in entry.splitlines()[1:]
+           if " = " in line and " parameter(" not in line]
+    assert len(ops) == 1, ops
+    assert ops[0].startswith("ROOT %gaussian_blur_halo.")
+    assert 'custom_call_target="tpu_custom_call"' in ops[0]
+    assert not any(" fusion(" in line for line in entry.splitlines())
+
+
 def test_matmul_compiles_for_v5e_in_bf16(one_chip):
     from repro.kernels import matmul
 

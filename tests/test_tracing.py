@@ -176,6 +176,62 @@ def test_issue_spans_count_the_packages_served(traced):
     assert len(_spans(events, "coexec.retire")) == served
 
 
+def _check_fills(events, h, halo, split_args):
+    """One ``coexec.fill`` per split argument of each piece of ``h``'s
+    launch, each inside its package's ``coexec.stage``; for each
+    argument, their rows cover the packages."""
+    stages = {(ids["unit"], ids["pkg"]): (s, e)
+              for s, e, ids in _spans(events, "coexec.stage")
+              if ids["launch"] == h.launch_id}
+    fills = [(s, e, ids) for s, e, ids in _spans(events, "coexec.fill")
+             if ids["launch"] == h.launch_id]
+    assert len(fills) == split_args * h.stats.data.pieces > 0
+    for s, e, ids in fills:
+        assert set(ids) == {"launch", "unit", "pkg", "rows", "halo"}, ids
+        assert ids["halo"] == halo
+        lo, hi = stages[(ids["unit"], ids["pkg"])]
+        assert lo <= s <= e <= hi, (ids, (s, e), (lo, hi))
+    assert sum(ids["rows"] for *_, ids in fills) == split_args * sum(
+        p.size for p in h.stats.packages)
+    return fills
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_fill_spans_one_a_piece_inside_the_stage(traced, depth):
+    """Mandelbrot splits two arguments, so two fills a piece."""
+    handles, events, _ = traced
+    _check_fills(_own(events, depth), handles[depth], halo=0, split_args=2)
+
+
+def test_fill_spans_of_a_halo_split_into_pieces(tmp_path, monkeypatch):
+    """The Gaussian under static 0.4/0.6, each package split in two where
+    it can be: 256 rows on one unit, 256 + 128 on the other, one fill a
+    piece, each carrying the kernel's 2-row halo."""
+    from repro.core import dataplane
+
+    monkeypatch.setattr(dataplane, "_MIN_SPLIT_BYTES", 0)
+    rows = 640
+    kernel = build_kernel("gaussian")
+    inputs = kernel_demo_inputs("gaussian", rows, seed=5)
+    units = counits_from_devices(jax.local_devices()[:1] * 2,
+                                 kinds=["cpu", "cpu"],
+                                 speed_hints=[0.4, 0.6])
+    spec = _spec(1).replace(scheduler=_spec(1).scheduler.replace(
+        policy="static"))
+    with spec.runtime(units=units) as rt:
+        rt.launch(rows, kernel, inputs)        # compile outside the trace
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            h = rt.launch_async(rows, kernel, inputs)
+            h.result(timeout=120)
+        finally:
+            jax.profiler.stop_trace()
+    fills = _check_fills(_read(str(tmp_path)), h, halo=2, split_args=1)
+    assert sorted(ids["rows"] for *_, ids in fills) == [128, 256, 256]
+
+
 def test_engine_launch_stats_stamp_submit_before_first_issue(traced):
     handles, _, _ = traced
     for h in handles.values():
